@@ -44,6 +44,7 @@ import numpy as np                                        # noqa: E402
 
 from benchmarks import resources                          # noqa: E402
 from benchmarks.provenance import provenance              # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.obs import NOOP_OBS                            # noqa: E402
 
 RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results"
@@ -706,6 +707,7 @@ def _select_benches(only: str, benches: dict) -> dict:
 
 def main():
     global OBS
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run only these benches (comma-separated, e.g. "

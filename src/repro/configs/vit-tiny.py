@@ -3,8 +3,13 @@
 32x32x3 inputs, patch size 4, 12 blocks, d=192, 3 heads, MLP 768, GELU;
 MoCo v3 heads attach on top (repro.core.heads). This is the FL/SSL
 experiment backbone, not part of the 40-pair dry-run table.
+
+``TRAIN`` turns on per-block rematerialization: one client's full-width
+MoCo v3 step at the published batch of 1024 needs about 26 GB of
+activations without it, more than a 16 GB TPU v5e holds, and under 3 GB
+with it.
 """
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, TrainConfig
 
 CONFIG = ModelConfig(
     arch_id="vit-tiny", family="dense",
@@ -12,3 +17,5 @@ CONFIG = ModelConfig(
     d_ff=768, vocab_size=0, causal=False, act="gelu",
     source="arXiv:2010.11929 (ViT); paper Section 5.1",
 )
+
+TRAIN = TrainConfig(remat=True)

@@ -40,14 +40,17 @@ class Encoder:
     num_stages: int
 
 
-def make_vit_encoder(cfg, image_size: int = 32, patch_size: int = 4) -> Encoder:
+def make_vit_encoder(cfg, image_size: int = 32, patch_size: int = 4,
+                     remat: bool = False) -> Encoder:
+    """``remat`` checkpoints each block (``TrainConfig.remat``): the
+    backward pass recomputes block activations instead of keeping them."""
     def init(key):
         return vit_mod.init_vit(key, cfg, image_size, patch_size)
 
     def apply(params, x, sub_layers=None, active_from=0, layer_gates=None):
         return vit_mod.vit_forward(params, x, cfg, patch_size=patch_size,
                                    sub_layers=sub_layers,
-                                   active_from=active_from,
+                                   active_from=active_from, remat=remat,
                                    layer_gates=layer_gates)
 
     return Encoder(init, apply, cfg.d_model, cfg.num_layers)

@@ -199,7 +199,8 @@ def run_fedssl(model_cfg, ssl_cfg, fl, train_cfg, *, images, client_indices,
     prv = make_privacy(privacy)
     k_privacy = PrivacyEngine.fork_stream(key) if prv is not None else None
     if encoder is None:
-        encoder = ssl_mod.make_vit_encoder(model_cfg, image_size)
+        encoder = ssl_mod.make_vit_encoder(model_cfg, image_size,
+                                           remat=train_cfg.remat)
     k_init, key = jax.random.split(key)
     state = ssl_mod.ssl_init(k_init, encoder, ssl_cfg)
     opt = make_optimizer(train_cfg)

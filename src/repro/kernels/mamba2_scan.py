@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
                 chunk: int):
@@ -94,7 +92,7 @@ def ssd_scan_bshpn(xh, dt, a, Bm, Cm, *, chunk: int = 128,
                                lambda b, h, c: (b, c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, P), xh.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "arbitrary")),
         interpret=interpret,

@@ -8,8 +8,10 @@ tiles — phase 0 accumulates the per-column absmax into a persistent VMEM
 scratch, phase 1 turns it into the dequant scale (``max(amax, 1e-12) /
 127``) and emits the clipped/rounded int8 payload — so each element is
 read exactly twice and written once, with no dense fp32 intermediates.
-The math is bit-identical to ``transport.Int8Codec`` (same IEEE fp32 ops,
-round-half-even).
+The grid walks column tiles outermost, so a block is at most (256, 512)
+fp32 whatever the channel count (the 4096x4096 projector weight included)
+and stays well inside the default scoped VMEM. The math is bit-identical
+to ``transport.Int8Codec`` (same IEEE fp32 ops, round-half-even).
 
 top-k (``compensate`` / ``topk_ef_update``): the XLA path materializes the
 delta, the compensated delta, |delta| and the post-selection residual as
@@ -18,8 +20,11 @@ separate dense buffers. ``compensate`` fuses delta + error-feedback add +
 given the k-th magnitude threshold it zeroes every *selected* entry of the
 compensated delta in one pass, using a sequential-grid running count so
 ``|x| == threshold`` ties are broken exactly like ``lax.top_k`` (lowest
-index first, up to the ``needed`` count). What's left *is* the new
-error-feedback residual — dropped mass, nothing else.
+index first, up to the ``needed`` count). The prefix counts of tied
+entries are two triangular 0/1 matmuls on the MXU (Pallas TPU has no
+``cumsum``); every operand is a small integer, so bf16 inputs with fp32
+accumulation count exactly. What's left *is* the new error-feedback
+residual — dropped mass, nothing else.
 
 Oracles in ref.py; parity tests in tests/test_kernels.py (interpret mode).
 """
@@ -32,15 +37,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import make_compiler_params
-
 LANE = 128
 
 
-def _pad2(x, br):
-    """Pad (R, C) up to (multiple of br, multiple of LANE)."""
+def _pad2(x, br, bc=LANE):
+    """Pad (R, C) up to (multiple of br, multiple of bc)."""
     R, C = x.shape
-    pr, pc = (-R) % br, (-C) % LANE
+    pr, pc = (-R) % br, (-C) % bc
     if pr or pc:
         x = jnp.pad(x, ((0, pr), (0, pc)))
     return x, R, C
@@ -50,8 +53,8 @@ def _pad2(x, br):
 # int8 per-channel (per-column) symmetric quantization
 # ---------------------------------------------------------------------------
 def _int8_quant_kernel(x_ref, q_ref, s_ref, amax_ref):
-    phase = pl.program_id(0)
-    tile = pl.program_id(1)
+    phase = pl.program_id(1)
+    tile = pl.program_id(2)
 
     @pl.when((phase == 0) & (tile == 0))
     def _init():
@@ -72,26 +75,30 @@ def _int8_quant_kernel(x_ref, q_ref, s_ref, amax_ref):
                               -127, 127).astype(jnp.int8)
 
 
-def int8_quant_matrix(x, *, br: int = 256, interpret: bool = False):
+def int8_quant_matrix(x, *, br: int = 256, bc: int = 512,
+                      interpret: bool = False):
     """x: (R, C) fp32 -> (q (R, C) int8, scale (C,) fp32), scale per column
-    (``= max(absmax, 1e-12) / 127``), q = clip(round(x / scale))."""
-    xp, R, C = _pad2(x, br)
+    (``= max(absmax, 1e-12) / 127``), q = clip(round(x / scale)). Grid:
+    (column tile, phase, row tile) — each column tile reduces its absmax
+    over every row tile, then quantizes them."""
+    bc = min(bc, -(-x.shape[1] // LANE) * LANE)
+    xp, R, C = _pad2(x, br, bc)
     Rp, Cp = xp.shape
     q, s = pl.pallas_call(
         _int8_quant_kernel,
-        grid=(2, Rp // br),
-        in_specs=[pl.BlockSpec((br, Cp), lambda p, i: (i, 0))],
+        grid=(Cp // bc, 2, Rp // br),
+        in_specs=[pl.BlockSpec((br, bc), lambda j, p, i: (i, j))],
         out_specs=[
-            pl.BlockSpec((br, Cp), lambda p, i: (i, 0)),
-            pl.BlockSpec((1, Cp), lambda p, i: (0, 0)),
+            pl.BlockSpec((br, bc), lambda j, p, i: (i, j)),
+            pl.BlockSpec((1, bc), lambda j, p, i: (0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Rp, Cp), jnp.int8),
             jax.ShapeDtypeStruct((1, Cp), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, Cp), jnp.float32)],
-        compiler_params=make_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((1, bc), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(xp)
     return q[:R, :C], s[0, :C]
@@ -116,7 +123,7 @@ def int8_dequant_matrix(q, scale, *, br: int = 256,
         ],
         out_specs=pl.BlockSpec((br, Cp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, Cp), jnp.float32),
-        compiler_params=make_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(qp, sp)
@@ -155,7 +162,7 @@ def compensate(flat, ref, res, *, br: int = 256, interpret: bool = False):
         in_specs=[pl.BlockSpec((br, Cp), lambda i: (i, 0))] * 3,
         out_specs=[pl.BlockSpec((br, Cp), lambda i: (i, 0))] * 2,
         out_shape=[jax.ShapeDtypeStruct((Rp, Cp), jnp.float32)] * 2,
-        compiler_params=make_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(f2, r2, e2)
@@ -176,16 +183,24 @@ def _ef_update_kernel(c_ref, a_ref, t_ref, k_ref, o_ref, cnt_ref):
     gt = a > thresh
     eq = a == thresh
     # global row-major rank (1-based) of each ==threshold entry: within-row
-    # cumsum + exclusive prefix of per-row totals + the running count
-    # carried across tiles in SMEM (the grid is sequential).
-    eqi = eq.astype(jnp.int32)
-    row = jnp.cumsum(eqi, axis=1)
-    row_tot = row[:, -1:]
-    prior = jnp.cumsum(row_tot, axis=0) - row_tot
-    rank = row + prior + cnt_ref[0]
+    # inclusive prefix (eq @ upper-triangular ones) + exclusive prefix of
+    # the per-row totals (strictly-lower-triangular ones @ totals) + the
+    # running count carried across tiles in SMEM (the grid is sequential)
+    br, cols = c.shape
+    eqf = eq.astype(jnp.float32)
+    upper = (jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 0)
+             <= jax.lax.broadcasted_iota(jnp.int32, (cols, cols), 1))
+    row = jnp.dot(eqf, upper.astype(jnp.float32),
+                  preferred_element_type=jnp.float32)
+    row_tot = jnp.broadcast_to(row[:, cols - 1:], (br, cols))
+    lower = (jax.lax.broadcasted_iota(jnp.int32, (br, br), 1)
+             < jax.lax.broadcasted_iota(jnp.int32, (br, br), 0))
+    prior = jnp.dot(lower.astype(jnp.float32), row_tot,
+                    preferred_element_type=jnp.float32)
+    rank = (row + prior).astype(jnp.int32) + cnt_ref[0]
     selected = gt | (eq & (rank <= needed))
     o_ref[...] = jnp.where(selected, 0.0, c)
-    cnt_ref[0] = cnt_ref[0] + row[-1, -1] + prior[-1, 0]
+    cnt_ref[0] = cnt_ref[0] + jnp.sum(eq.astype(jnp.int32))
 
 
 def topk_ef_update(comp, thresh, needed, *, br: int = 256,
@@ -218,7 +233,7 @@ def topk_ef_update(comp, thresh, needed, *, br: int = 256,
         out_specs=pl.BlockSpec((br, Cp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, Cp), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        compiler_params=make_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(c2, a2, thresh.reshape(1), needed.reshape(1))

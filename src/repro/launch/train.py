@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import (FLConfig, SSLConfig, TrainConfig, load_arch,
-                                reduced)
+                                load_train, reduced)
 from repro.core import schedule as sched
 from repro.core import ssl as ssl_mod
 from repro.data import iid_partition, dirichlet_partition, synthetic_images
@@ -54,6 +54,7 @@ from repro.federated import fleet as fleet_mod
 from repro.federated import simulation as sim_mod
 from repro.federated import transport as transport_mod
 from repro.federated.driver import run_fedssl
+from repro.launch.compile_cache import enable_compile_cache
 from repro.federated import eval as fl_eval
 from repro.obs import (ConsoleRenderer, format_round_line, make_obs,
                        write_history_json)
@@ -107,17 +108,29 @@ def export_obs(obs, args, hist=None):
     return written
 
 
+def vit_configs(layers: int = 0, d_model: int = 0):
+    """(model, SSL, training) configs for ``--mode vit``. With neither
+    size given this is the published ViT-Tiny with the ``SSLConfig``
+    default MoCo v3 heads and the arch's own ``TRAIN`` (remat on); either
+    size given selects the reduced CPU variant (4 layers, d=64 for the one
+    left out), with 256/256/64 heads."""
+    if not (layers or d_model):
+        return load_arch("vit-tiny"), SSLConfig(), load_train("vit-tiny")
+    layers, d_model = layers or 4, d_model or 64
+    cfg = reduced(load_arch("vit-tiny"), num_layers=layers, d_model=d_model,
+                  num_heads=4, num_kv_heads=4, d_ff=2 * d_model)
+    return (cfg, SSLConfig(proj_hidden=256, pred_hidden=256, proj_dim=64),
+            TrainConfig())
+
+
 def train_vit(args):
     key = jax.random.PRNGKey(args.seed)
-    cfg = reduced(load_arch("vit-tiny"), num_layers=args.layers,
-                  d_model=args.d_model,
-                  num_heads=4, num_kv_heads=4, d_ff=2 * args.d_model)
-    ssl_cfg = SSLConfig(proj_hidden=256, pred_hidden=256, proj_dim=64)
+    cfg, ssl_cfg, tc = vit_configs(args.layers, args.d_model)
+    tc = dataclasses.replace(tc, batch_size=args.batch)
     fl = FLConfig(num_clients=args.clients, rounds=args.rounds,
                   local_epochs=args.local_epochs, schedule=args.schedule,
                   server_epochs=1, depth_dropout=args.depth_dropout,
                   clients_per_round=args.clients_per_round)
-    tc = TrainConfig(batch_size=args.batch, base_lr=1.5e-4)
     kd, key = jax.random.split(key)
     images, labels = synthetic_images(kd, args.samples, 10, 32)
     if args.dirichlet_beta > 0:
@@ -435,6 +448,7 @@ def make_sim_from_args(args, num_clients):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=("vit", "lm"), default="vit")
     ap.add_argument("--arch", default="internlm2-1.8b")
@@ -501,8 +515,12 @@ def main():
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--samples", type=int, default=1024)
     ap.add_argument("--seq-len", type=int, default=128)
-    ap.add_argument("--layers", type=int, default=4)
-    ap.add_argument("--d-model", type=int, default=64)
+    ap.add_argument("--layers", type=int, default=0,
+                    help="vit: encoder depth of the reduced CPU variant "
+                         "(0 with --d-model 0 = the published ViT-Tiny)")
+    ap.add_argument("--d-model", type=int, default=0,
+                    help="vit: width of the reduced CPU variant (0 with "
+                         "--layers 0 = the published ViT-Tiny)")
     ap.add_argument("--depth-dropout", type=float, default=0.0)
     ap.add_argument("--dirichlet-beta", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)

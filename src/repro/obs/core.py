@@ -7,9 +7,9 @@ everything off, near-zero overhead) and record unconditionally.
 
 ``make_obs(trace=..., metrics=..., profile_dir=...)`` builds an enabled
 bundle; ``obs.export(...)`` writes whichever artifacts were requested
-(JSONL trace, Chrome trace, metrics CSV). The profiler hooks are gated:
-if ``jax.profiler`` is unavailable or fails to start (headless builds),
-the run proceeds untraced rather than crashing.
+(JSONL trace, Chrome trace, metrics CSV). A run given a profile directory
+fails if ``jax.profiler`` cannot start or stop; it never carries on
+untraced.
 """
 from __future__ import annotations
 
@@ -43,26 +43,20 @@ class Observability:
                 or self.profile_dir is not None
                 or self.health is not None)
 
-    # -- jax.profiler hooks (gated: failure to start is non-fatal) ----------
+    # -- jax.profiler hooks: a run asked to profile fails if it cannot ------
     def start_profiler(self):
         if self.profile_dir is None or self._profiling:
             return
-        try:
-            import jax
-            jax.profiler.start_trace(self.profile_dir)
-            self._profiling = True
-        except Exception as e:          # pragma: no cover - env dependent
-            print(f"obs: jax.profiler unavailable ({e}); continuing")
+        import jax
+        jax.profiler.start_trace(self.profile_dir)
+        self._profiling = True
 
     def stop_profiler(self):
         if not self._profiling:
             return
-        try:
-            import jax
-            jax.profiler.stop_trace()
-        except Exception as e:          # pragma: no cover - env dependent
-            print(f"obs: jax.profiler stop failed ({e})")
+        import jax
         self._profiling = False
+        jax.profiler.stop_trace()
 
     # -- artifact export -----------------------------------------------------
     def export(self, *, trace_jsonl=None, chrome_trace=None,

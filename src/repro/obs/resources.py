@@ -90,20 +90,21 @@ def _peak_rss_bytes() -> int:
 def device_memory_snapshot(device=None) -> dict:
     """Live memory watermark for ``device`` (default: first device).
 
-    Accelerator backends expose allocator stats via
-    ``device.memory_stats()``; the CPU backend returns None, so there we
-    fall back to the process RSS (``/proc/self/statm``) and its
-    high-water mark (``VmHWM``) — CPU arrays live on the host heap, so
-    RSS *is* the device watermark. ``source`` records which path
-    produced the numbers."""
+    An accelerator reports its allocator stats via
+    ``device.memory_stats()``; one that reports none is an error, never a
+    host number in the device's place. The CPU backend reports none, so
+    there the process RSS (``/proc/self/statm``) and its high-water mark
+    (``VmHWM``) stand in — CPU arrays live on the host heap, so RSS *is*
+    the device watermark. ``source`` records which path produced the
+    numbers."""
     if device is None:
         device = jax.devices()[0]
-    stats = None
-    try:
+    if device.platform != "cpu":
         stats = device.memory_stats()
-    except Exception:
-        stats = None
-    if stats:
+        if not stats:
+            raise RuntimeError(f"{device.platform} device "
+                               f"{device.device_kind!r} reports no "
+                               f"memory_stats()")
         in_use = int(stats.get("bytes_in_use", 0))
         return {"source": "device", "bytes_in_use": in_use,
                 "peak_bytes": int(stats.get("peak_bytes_in_use", in_use))}

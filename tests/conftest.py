@@ -1,16 +1,17 @@
 import os
 import sys
-import tempfile
 
 # tests run on the single real CPU device (the dry-run, and only the
 # dry-run, forces 512 host devices in its own process)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # the FL integration tests build many small engines that jit the same
 # round programs; the persistent cache deserializes repeat compilations
-# (including across pytest runs) instead of re-lowering them
+# (including across pytest runs) instead of re-lowering them. Same default
+# as the program's own (repro.launch.compile_cache): <repo>/.jax_cache
 os.environ.setdefault(
     "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(tempfile.gettempdir(), "repro-jax-cache"))
+    os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                 ".jax_cache")))
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

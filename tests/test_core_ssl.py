@@ -112,3 +112,47 @@ def test_lm_ssl_loss_with_alignment(rng):
                                   cfg, sub_layers=2, active_from=1,
                                   global_params=params, align_weight=0.01)
     assert jnp.isfinite(loss) and "align" in m
+
+
+def test_remat_encoder_matches_plain(rng):
+    """``TrainConfig.remat`` reaches the ViT forward: per-block
+    checkpointing recomputes activations but leaves the loss and the
+    gradients as they were."""
+    plain = ssl_mod.make_vit_encoder(VIT)
+    remat = ssl_mod.make_vit_encoder(VIT, remat=True)
+    state = ssl_mod.ssl_init(rng, plain, SSLC)
+    x1, x2 = jax.random.normal(jax.random.PRNGKey(3), (2, 8, 32, 32, 3))
+
+    def loss_and_grads(enc):
+        def f(online):
+            return ssl_mod.ssl_loss({**state, "online": online}, x1, x2,
+                                    enc, SSLC)[0]
+        return jax.jit(jax.value_and_grad(f))(state["online"])
+
+    (l0, g0), (l1, g1) = loss_and_grads(plain), loss_and_grads(remat)
+    assert abs(float(l0) - float(l1)) <= 1e-6 * abs(float(l0))
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("sizes,published", [((0, 0), True),
+                                             ((2, 32), False),
+                                             ((3, 0), False)])
+def test_vit_configs_published_unless_sized(sizes, published):
+    """``--mode vit`` builds the published ViT-Tiny + MoCo v3 heads with
+    remat when neither ``--layers`` nor ``--d-model`` is given, else the
+    reduced CPU variant exactly as before (remat off, 256/256/64 heads)."""
+    from repro.launch.train import vit_configs
+    cfg, ssl_cfg, tc = vit_configs(*sizes)
+    if published:
+        assert (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.d_ff) == \
+            (12, 192, 3, 768)
+        assert ssl_cfg == SSLConfig() and tc.remat
+    else:
+        layers, d = sizes[0] or 4, sizes[1] or 64
+        assert (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.d_ff) == \
+            (layers, d, 4, 2 * d)
+        assert (ssl_cfg.proj_hidden, ssl_cfg.pred_hidden,
+                ssl_cfg.proj_dim) == (256, 256, 64)
+        assert not tc.remat and cfg.compute_dtype == "float32"

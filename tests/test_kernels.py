@@ -15,6 +15,7 @@ import pytest
 
 from repro.core.losses import info_nce
 from repro.kernels import ops, ref
+from repro.kernels import wire_codecs as wc
 
 
 def _tol(dtype):
@@ -234,4 +235,59 @@ def test_wire_topk_breaks_ties_like_top_k(interpret):
     dec = ops.wire_topk_decode(idx, val, flat.shape[0],
                                interpret=interpret)
     assert np.array_equal(np.asarray(dec), np.asarray(rdec))
+    assert np.array_equal(np.asarray(new_res), np.asarray(rres))
+
+
+def _multi_tile_leaves(rng):
+    """Slots spanning several (8, 128) tiles, at offsets and lengths that
+    are not tile multiples, plus slots smaller than one tile that share
+    tiles with their neighbours."""
+    k = jax.random.split(rng, 4)
+    leaves = [jax.random.normal(k[0], (3, 1000)),      # stacked, rows 1..2
+              jax.random.normal(k[1], (5000,)),
+              jax.random.normal(k[2], (192,)),
+              jax.random.normal(k[3], (4103,))]
+    layout = ((1000, 0, 2000), (0, 2000, 5000), (0, 7000, 192),
+              (0, 7192, 4103))
+    return leaves, layout, 11295
+
+
+@pytest.mark.parametrize("interpret", WIRE_MODES)
+def test_wire_pack_unpack_multi_tile(interpret, rng):
+    leaves, layout, total = _multi_tile_leaves(rng)
+    bases = [l.reshape(-1) for l in leaves]
+    got = ops.wire_pack(leaves, layout, total, interpret=interpret)
+    want = ref.wire_pack_ref(bases, layout, total)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    flat = jax.random.normal(jax.random.split(rng)[1], (total,))
+    lay4 = tuple((s, d, n, n == b.shape[0])
+                 for (s, d, n), b in zip(layout, bases))
+    outs = ops.wire_unpack(flat, bases, lay4, interpret=interpret)
+    for g, w in zip(outs, ref.wire_unpack_ref(flat, bases, layout)):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_int8_quant_column_tiles_match_codec_math(rng):
+    # three column tiles of 512 (the last one padded) and a padded row tile
+    x = jax.random.normal(rng, (300, 1100)) * 2.0
+    q, s = wc.int8_quant_matrix(x, interpret=True)
+    wq, ws = ref.int8_quant_ref(x)
+    assert q.shape == wq.shape and s.shape == ws.shape
+    assert np.abs(np.asarray(q).astype(np.int32)
+                  - np.asarray(wq).astype(np.int32)).max() <= 1
+    np.testing.assert_allclose(np.asarray(s), np.asarray(ws), rtol=1e-6)
+
+
+def test_topk_ef_update_ties_across_row_tiles():
+    # 3 row tiles of 256 x 128: tied magnitudes in every tile, so the
+    # running count must carry the tie rank from tile to tile
+    flat = jnp.asarray(np.tile(np.asarray([2.0, -1.0, 1.0, 0.5],
+                                          np.float32), 20000))
+    base = jnp.zeros_like(flat)
+    k = 30000        # 20000 entries of |x|=2, 10000 of the 40000 ties at 1
+    idx, _, new_res = ops.wire_topk_encode_ef(flat, base, None, k,
+                                              interpret=True)
+    ridx, _, rres, _ = ref.topk_ef_ref(flat, base, jnp.zeros_like(flat), k)
+    assert sorted(np.asarray(idx).tolist()) == \
+        sorted(np.asarray(ridx).tolist())
     assert np.array_equal(np.asarray(new_res), np.asarray(rres))
