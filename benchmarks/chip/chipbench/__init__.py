@@ -1,0 +1,5 @@
+"""The chip benchmark's own code: cell specs, inputs, the plain reference,
+the correctness comparison, the profiler-trace reduction and the analytic
+FLOP counts. Only ``harness`` (which drives the program's entry) and
+``faults`` (which plants faults in it for the limit readings and tests)
+import the program."""
