@@ -29,7 +29,8 @@ def make_local_step(encoder, ssl_cfg, opt, *, sub_layers: int,
     @jax.jit
     def step(state, opt_state, images, key, lr, global_enc):
         k_aug, k_dd = jax.random.split(key)
-        x1, x2 = two_views(k_aug, images)
+        with jax.named_scope("augment"):
+            x1, x2 = two_views(k_aug, images)
         gates = None
         if depth_dropout > 0.0:
             gates = sched.depth_dropout_gates(
@@ -44,11 +45,13 @@ def make_local_step(encoder, ssl_cfg, opt, *, sub_layers: int,
 
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["online"])
-        mask = stage_update_mask(state["online"], sub_layers, active_from)
-        new_online, opt_state = opt.update(grads, opt_state,
-                                           state["online"], lr, mask)
-        state = {**state, "online": new_online}
-        state = ssl_mod.momentum_update(state, ssl_cfg.momentum)
+        with jax.named_scope("optimizer"):
+            mask = stage_update_mask(state["online"], sub_layers,
+                                     active_from)
+            new_online, opt_state = opt.update(grads, opt_state,
+                                               state["online"], lr, mask)
+            state = {**state, "online": new_online}
+            state = ssl_mod.momentum_update(state, ssl_cfg.momentum)
         return state, opt_state, metrics
 
     return step

@@ -59,6 +59,7 @@ from repro.federated import engine as engine_mod
 from repro.federated import transport as transport_mod
 from repro.obs import NOOP_OBS, format_round_line
 from repro.obs import resources as obs_resources
+from repro.obs.trace import is_tracing
 from repro.privacy import PrivacyEngine, make_privacy
 from repro.optim import make_optimizer
 from repro.optim.schedules import learning_rate, scaled_base_lr
@@ -277,9 +278,11 @@ def run_fedssl(model_cfg, ssl_cfg, fl, train_cfg, *, images, client_indices,
                 key, ks = jax.random.split(key)
                 # with the default overcommit (1.0) this is byte-for-byte
                 # the historical sampling call — same key, same cohort
-                cohort = server.sample_clients(
-                    ks, fl.num_clients, fl.clients_per_round,
-                    overcommit=sim.overcommit if sim is not None else 1.0)
+                with tracer.span("fl.sample", cat="fl"):
+                    cohort = server.sample_clients(
+                        ks, fl.num_clients, fl.clients_per_round,
+                        overcommit=sim.overcommit if sim is not None
+                        else 1.0)
                 # download direction: clients (and the alignment loss's
                 # global model) see the wire-decoded broadcast, not the
                 # server pytree
@@ -429,7 +432,7 @@ def run_fedssl(model_cfg, ssl_cfg, fl, train_cfg, *, images, client_indices,
                     wire_upload_bytes=up["wire_bytes"],
                     participants=len(participants),
                     dropped=len(outcome.dropped) if outcome else 0)
-                if obs.enabled:
+                if is_tracing(tracer):
                     # live watermark (mem.* attrs are excluded from
                     # Tracer.structure(): environment, not structure)
                     round_span.set(**obs_resources.memory_span_attrs())

@@ -129,8 +129,11 @@ def build_round_program(client_init, client_step, extract,
                 batch = jax.tree.map(lambda a: a[i], shard)
                 nc, loss = client_step(c, batch, k, lr, broadcast)
                 keep = functools.partial(jnp.where, v)
-                return (jax.tree.map(keep, nc, c),
-                        jnp.where(v, loss, last)), None
+                # XLA fuses the optimizer's elementwise update into this
+                # select, and a fusion takes its root's scope
+                with jax.named_scope("optimizer"):
+                    nc = jax.tree.map(keep, nc, c)
+                return (nc, jnp.where(v, loss, last)), None
 
             carry0 = (client_init(broadcast), jnp.float32(0.0))
             (c, last), _ = jax.lax.scan(body, carry0, (idx, keys, ok))
@@ -145,18 +148,21 @@ def build_round_program(client_init, client_step, extract,
                                        step_keys, valid, lr)
             if not fedavg:
                 return outs, losses
-            return aggregate.fedavg_stacked(outs, weights), losses
+            with jax.named_scope("fedavg"):
+                return aggregate.fedavg_stacked(outs, weights), losses
     else:
         def round_fn(broadcast, shards, batch_idx, step_keys, valid,
                      weights, lr, residuals):
             outs, losses = run_clients(broadcast, shards, batch_idx,
                                        step_keys, valid, lr)
-            decoded, new_res, scales = wire_transform(outs, broadcast,
-                                                      residuals)
+            with jax.named_scope("wire"):
+                decoded, new_res, scales = wire_transform(outs, broadcast,
+                                                          residuals)
             if not fedavg:
                 return decoded, losses, new_res, scales
-            return (aggregate.fedavg_stacked(decoded, weights), losses,
-                    new_res, scales)
+            with jax.named_scope("fedavg"):
+                avg = aggregate.fedavg_stacked(decoded, weights)
+            return avg, losses, new_res, scales
 
     return jax.jit(round_fn)
 
@@ -346,32 +352,35 @@ class VmapEngine:
     def run_round(self, state, plan, participants, client_keys, lr,
                   global_enc, server_online, collect=False):
         bs = self.train_cfg.batch_size
-        idxs, keys, valids = [], [], []
-        for i, kc in zip(participants, client_keys):
-            bi, sk, v = client_mod.replay_batch_plan(
-                kc, self.counts[i], self.fl.local_epochs, bs,
-                self.total_steps)
-            idxs.append(bi)
-            keys.append(sk)
-            valids.append(v)
-        if list(participants) == self._all:
-            if self._full_shards is None:
-                self._full_shards = self._gather(self._pad_idx)
-            shards, w = self._full_shards, self._all_weights
-        else:
-            pidx = jnp.asarray(np.asarray(participants, np.int32))
-            shards = self._gather(self._pad_idx[pidx])
-            w = aggregate.client_weights(
-                [self.counts[i] for i in participants])
-        spec = self.transport.plan_specs(server_online, plan)["upload"]
-        residuals = self.transport.gather_residuals(participants, spec)
+        tracer = self.obs.tracer
+        # host work the round program waits for: the batch plans, the
+        # shard gather and the payload layout
+        with tracer.span("engine.plan", cat="engine"):
+            idxs, keys, valids = [], [], []
+            for i, kc in zip(participants, client_keys):
+                bi, sk, v = client_mod.replay_batch_plan(
+                    kc, self.counts[i], self.fl.local_epochs, bs,
+                    self.total_steps)
+                idxs.append(bi)
+                keys.append(sk)
+                valids.append(v)
+            if list(participants) == self._all:
+                if self._full_shards is None:
+                    self._full_shards = self._gather(self._pad_idx)
+                shards, w = self._full_shards, self._all_weights
+            else:
+                pidx = jnp.asarray(np.asarray(participants, np.int32))
+                shards = self._gather(self._pad_idx[pidx])
+                w = aggregate.client_weights(
+                    [self.counts[i] for i in participants])
+            spec = self.transport.plan_specs(server_online, plan)["upload"]
+            residuals = self.transport.gather_residuals(participants, spec)
         # the whole round — every client's local steps, the in-program
-        # wire path and FedAvg — is one dispatch, so this span *is* the
-        # device time; per-client structure only exists inside XLA
-        with self.obs.tracer.span("engine.dispatch", cat="engine",
-                                  engine=self.name,
-                                  participants=len(participants),
-                                  programs=len(self._programs)):
+        # wire path and FedAvg — is one dispatch; this span closes once
+        # the program is enqueued, and the wait for it is engine.readback
+        with tracer.span("engine.dispatch", cat="engine",
+                         engine=self.name, participants=len(participants),
+                         programs=len(self._programs)):
             result, losses, new_res, scales = self._program(
                 plan, spec, fedavg=not collect)(
                 {"state": state, "global_enc": global_enc,
@@ -386,9 +395,12 @@ class VmapEngine:
             result = [jax.tree.map(lambda a, i=i: a[i], result)
                       for i in range(len(participants))]
         stats = dict(self.transport.upload_stats(spec))
-        stats["clip_fraction"] = float(
-            np.mean(np.asarray(scales, np.float32) < 1.0))
-        return result, [float(x) for x in np.asarray(losses)], stats
+        # the host waits here for the round program to finish
+        with tracer.span("engine.readback", cat="engine"):
+            stats["clip_fraction"] = float(
+                np.mean(np.asarray(scales, np.float32) < 1.0))
+            losses = [float(x) for x in np.asarray(losses)]
+        return result, losses, stats
 
 
 def make_engine(name: str, **kw):

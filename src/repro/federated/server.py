@@ -18,20 +18,23 @@ def make_calibration_step(encoder, ssl_cfg, opt, *, sub_layers: int):
     """End-to-end SSL step over the current sub-model (active_from=0)."""
     @jax.jit
     def step(state, opt_state, images, key, lr):
-        x1, x2 = two_views(key, images)
+        with jax.named_scope("calibrate"):
+            with jax.named_scope("augment"):
+                x1, x2 = two_views(key, images)
 
-        def loss_fn(online):
-            st = {**state, "online": online}
-            return ssl_mod.ssl_loss(st, x1, x2, encoder, ssl_cfg,
-                                    sub_layers=sub_layers, active_from=0)
+            def loss_fn(online):
+                st = {**state, "online": online}
+                return ssl_mod.ssl_loss(st, x1, x2, encoder, ssl_cfg,
+                                        sub_layers=sub_layers, active_from=0)
 
-        (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state["online"])
-        mask = stage_update_mask(state["online"], sub_layers, 0)
-        new_online, opt_state = opt.update(grads, opt_state,
-                                           state["online"], lr, mask)
-        state = {**state, "online": new_online}
-        state = ssl_mod.momentum_update(state, ssl_cfg.momentum)
+            (loss, metrics), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state["online"])
+            with jax.named_scope("optimizer"):
+                mask = stage_update_mask(state["online"], sub_layers, 0)
+                new_online, opt_state = opt.update(grads, opt_state,
+                                                   state["online"], lr, mask)
+                state = {**state, "online": new_online}
+                state = ssl_mod.momentum_update(state, ssl_cfg.momentum)
         return state, opt_state, metrics
 
     return step

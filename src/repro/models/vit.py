@@ -75,15 +75,17 @@ def vit_forward(params, images, cfg, *, patch_size: int = 4,
     gates = (jnp.ones((cfg.num_layers,), jnp.float32)
              if layer_gates is None else layer_gates)
     if act > 0:
-        (x, _), _ = jax.lax.scan(body, (x, jnp.float32(0.0)),
-                                 (_slice_stack(params["blocks"], 0, act),
-                                  gates[0:act]),
-                                 unroll=scan_cfg.scan_unroll())
-        x = jax.lax.stop_gradient(x)
+        with jax.named_scope("frozen"):
+            (x, _), _ = jax.lax.scan(
+                body, (x, jnp.float32(0.0)),
+                (_slice_stack(params["blocks"], 0, act), gates[0:act]),
+                unroll=scan_cfg.scan_unroll())
+            x = jax.lax.stop_gradient(x)
     if sub > act:
-        (x, _), _ = jax.lax.scan(body, (x, jnp.float32(0.0)),
-                                 (_slice_stack(params["blocks"], act, sub),
-                                  gates[act:sub]),
-                                 unroll=scan_cfg.scan_unroll())
+        with jax.named_scope("trained"):
+            (x, _), _ = jax.lax.scan(
+                body, (x, jnp.float32(0.0)),
+                (_slice_stack(params["blocks"], act, sub), gates[act:sub]),
+                unroll=scan_cfg.scan_unroll())
     x = B.rmsnorm(params["final_ln"], x, cfg.norm_eps)
     return x[:, 0]

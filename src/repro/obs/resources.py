@@ -92,11 +92,13 @@ def device_memory_snapshot(device=None) -> dict:
 
     An accelerator reports its allocator stats via
     ``device.memory_stats()``; one that reports none is an error, never a
-    host number in the device's place. The CPU backend reports none, so
-    there the process RSS (``/proc/self/statm``) and its high-water mark
-    (``VmHWM``) stand in — CPU arrays live on the host heap, so RSS *is*
-    the device watermark. ``source`` records which path produced the
-    numbers."""
+    host number in the device's place. Its peak is the larger of the
+    buffers' peak (``peak_bytes_in_use``) and the reservations' peak
+    (``peak_bytes_reserved``): on TPU a program's temporaries count only
+    in the latter. The CPU backend reports none, so there the process RSS
+    (``/proc/self/statm``) and its high-water mark (``VmHWM``) stand in —
+    CPU arrays live on the host heap, so RSS *is* the device watermark.
+    ``source`` records which path produced the numbers."""
     if device is None:
         device = jax.devices()[0]
     if device.platform != "cpu":
@@ -106,8 +108,10 @@ def device_memory_snapshot(device=None) -> dict:
                                f"{device.device_kind!r} reports no "
                                f"memory_stats()")
         in_use = int(stats.get("bytes_in_use", 0))
+        peak = max(int(stats.get("peak_bytes_in_use", in_use)),
+                   int(stats.get("peak_bytes_reserved", 0)))
         return {"source": "device", "bytes_in_use": in_use,
-                "peak_bytes": int(stats.get("peak_bytes_in_use", in_use))}
+                "peak_bytes": peak}
     try:
         with open("/proc/self/statm") as f:
             rss = int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")

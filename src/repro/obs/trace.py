@@ -21,31 +21,66 @@ Besides wall-clock spans the tracer holds named *virtual tracks*
 out on the simulated timeline. Exporters render tracks as threads, so a
 simulated 1000-client round reads like a real profile in Perfetto.
 
+One span set, two sinks: every wall-clock span, of ``Tracer`` and of
+``NOOP_TRACER`` alike, also enters ``jax.profiler.TraceAnnotation(name)``
+while a profile is recording, so the program's spans lie on the host
+plane of the profiler's trace, on the device timeline's clock. Their
+attributes are formatted into the annotation only then.
+
 ``NOOP_TRACER`` implements the same surface as no-ops; instrumented code
-holds an unconditional reference and pays only an attribute lookup and an
-empty context manager when observability is off (<2% on the engine
-bench — see docs/observability.md).
+holds an unconditional reference and pays only an attribute lookup, one
+profiler-state query and an empty context manager when observability is
+off (<2% on the engine bench — see docs/observability.md).
 """
 from __future__ import annotations
 
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 MAIN_TRACK = "main"
 
 
-class Span:
+class _ProfiledSpan:
+    """A span the profiler records: ``TraceAnnotation(name)`` from enter
+    to exit when a profile is recording at enter, nothing otherwise."""
+
+    __slots__ = ("name", "args", "_ann")
+
+    def __init__(self, name, args):
+        self.name = name
+        self.args = args
+        self._ann = None
+
+    def set(self, **attrs):
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+        return self
+
+    def __enter__(self):
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name, **self.args)
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        return False
+
+
+class Span(_ProfiledSpan):
     """An open span; a context manager. ``set(**attrs)`` attaches
     attributes any time before exit."""
 
-    __slots__ = ("tracer", "name", "cat", "args", "seq", "parent",
-                 "depth", "_t0")
+    __slots__ = ("tracer", "cat", "seq", "parent", "depth", "_t0")
 
     def __init__(self, tracer, name, cat, args, seq, parent, depth, t0):
+        super().__init__(name, args)
         self.tracer = tracer
-        self.name = name
         self.cat = cat
-        self.args = args
         self.seq = seq
         self.parent = parent
         self.depth = depth
@@ -53,12 +88,10 @@ class Span:
 
     def set(self, **attrs):
         self.args.update(attrs)
-        return self
-
-    def __enter__(self):
-        return self
+        return super().set(**attrs)
 
     def __exit__(self, *exc):
+        super().__exit__(*exc)
         self.tracer._close(self)
         return False
 
@@ -155,14 +188,17 @@ class _NoopSpan:
 
 
 class NoopTracer:
-    """Same surface as ``Tracer``; does nothing. A singleton
-    (``NOOP_TRACER``) so disabled instrumentation allocates nothing."""
+    """Same surface as ``Tracer``; records nothing in memory. A singleton
+    (``NOOP_TRACER``); its spans reach the profiler's trace while a
+    profile is recording, and allocate nothing otherwise."""
 
     events: List[Dict[str, Any]] = []
     meta: Dict[str, Any] = {}
     _span = _NoopSpan()
 
     def span(self, name, cat="fl", **attrs):
+        if TraceAnnotation.is_enabled():
+            return _ProfiledSpan(name, attrs)
         return self._span
 
     def instant(self, name, cat="fl", **attrs):

@@ -7,6 +7,7 @@ paper's per-schedule comm ratios from traces alone.
 import io
 import json
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,11 @@ from repro.obs import (NOOP_OBS, ConsoleRenderer, chrome_trace_doc,
 from repro.obs.core import Observability
 from repro.obs.metrics import NOOP_METRICS, MetricsRegistry
 from repro.obs.trace import NOOP_TRACER, Tracer, is_tracing
+from repro.core import schedule as sched
+from repro.core import ssl as ssl_mod
+from repro.federated import engine as engine_mod
+from repro.federated import server
+from repro.optim import make_optimizer
 
 CFG = ModelConfig("t-vit", "dense", 2, 32, 2, 2, 64, 0, causal=False,
                   compute_dtype="float32", act="gelu")
@@ -109,6 +115,28 @@ def test_noop_surfaces_do_nothing():
     assert NOOP_OBS.export(trace_jsonl="/nonexistent/x.jsonl") == {}
 
 
+def test_spans_reach_the_profiler_only_while_it_records(tmp_path):
+    """Both tracers' spans enter a ``TraceAnnotation`` while a profile
+    records, with their attributes; the no-op tracer keeps its
+    allocation-free singleton otherwise."""
+    assert NOOP_TRACER.span("x") is NOOP_TRACER.span("y")
+    t = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with NOOP_TRACER.span("noop.span", n=1) as sp:
+            sp.set(late=2)
+        with t.span("tracer.span", cat="fl", n=3) as sp:
+            sp.set(late=4)
+    finally:
+        jax.profiler.stop_trace()
+    assert NOOP_TRACER.events == []
+    assert [e["name"] for e in t.events] == ["tracer.span"]
+    assert t.events[0]["args"] == {"n": 3, "late": 4}
+    events = {ev.name: dict(ev.stats) for pl, ev in _host_events(tmp_path)}
+    assert events["noop.span"] == {"n": 1, "late": 2}
+    assert events["tracer.span"] == {"n": 3, "late": 4}
+
+
 def test_make_obs_enablement():
     assert not make_obs().enabled
     assert make_obs(trace=True).enabled
@@ -147,6 +175,124 @@ def test_observability_is_bit_identical(engine):
         assert np.array_equal(np.asarray(a), np.asarray(b))
         assert np.array_equal(np.asarray(a), np.asarray(c))
     assert h_off.loss == h_on.loss
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+    path = max(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
+    for pl in ProfileData.from_file(str(path)).planes:
+        if pl.name == "/host:CPU":
+            for ln in pl.lines:
+                for ev in ln.events:
+                    yield pl, ev
+
+
+def _profiled_run(trace_dir, **kw):
+    """``_run`` under ``jax.profiler``, Python tracer off (it would only
+    slow the test)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        return _run(schedule="e2e", **kw)
+    finally:
+        jax.profiler.stop_trace()
+
+
+@pytest.mark.parametrize("engine", ["sequential", "vmap"])
+def test_profiled_training_is_bit_identical(tmp_path, engine):
+    """Spans in the profiler's trace are host-side only: a run recorded
+    by ``jax.profiler`` trains byte-identically to one that is not."""
+    s_off, h_off = _run(engine=engine, obs=None, schedule="e2e")
+    s_on, h_on = _profiled_run(tmp_path, engine=engine, obs=None)
+    for a, b in zip(jax.tree.leaves(s_off["online"]),
+                    jax.tree.leaves(s_on["online"])):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert h_off.loss == h_on.loss
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_vmap_round_spans_in_the_profiler_trace(tmp_path, traced):
+    """One span set, two sinks: a 2-round vmap run under ``jax.profiler``
+    puts the driver's and the engine's spans on the trace's host plane,
+    with or without the in-memory tracer, which records the same names."""
+    obs = make_obs(trace=True) if traced else None
+    _profiled_run(tmp_path, engine="vmap", obs=obs)
+    names = [ev.name for _, ev in _host_events(tmp_path)]
+    want = ["round", "fl.sample", "engine.plan", "engine.dispatch",
+            "engine.readback"]
+    for name in want:
+        assert names.count(name) == 2, name
+    if traced:
+        recorded = [e["name"] for e in obs.tracer.events]
+        assert all(recorded.count(name) == 2 for name in want)
+
+
+# ---------------------------------------------------------------------------
+# device scopes in the programs' op_name metadata
+# ---------------------------------------------------------------------------
+_TRANSFORM = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
+
+
+def _scopes(hlo_text):
+    """The scope-like path components of the lowered program's op
+    locations, transforms stripped (``transpose(jvp(online))`` ->
+    ``online``)."""
+    out = set()
+    for loc in re.findall(r'loc\("([^"]*)"', hlo_text):
+        for part in loc.split("/"):
+            while (m := _TRANSFORM.match(part)):
+                part = m.group(1)
+            out.add(part)
+    return out
+
+
+def _stage2_programs():
+    key = jax.random.PRNGKey(0)
+    imgs, _ = synthetic_images(key, 96, 10, 32)
+    idx = [jnp.asarray(i) for i in iid_partition(96, 3)]
+    fl = FLConfig(num_clients=3, rounds=2, local_epochs=1,
+                  schedule="lw_fedssl", server_epochs=1)
+    encoder = ssl_mod.make_vit_encoder(CFG, 32)
+    opt = make_optimizer(TC)
+    eng = engine_mod.make_engine(
+        "vmap", encoder=encoder, ssl_cfg=SSLC, opt=opt, fl=fl,
+        train_cfg=TC, images=imgs, client_indices=idx)
+    plan = next(p for p in sched.build_schedule(fl, encoder.num_stages)
+                if p.active_from > 0 and p.align)
+    round_text = eng.lower_round(plan, clients=2).as_text(debug_info=True)
+    state = jax.eval_shape(lambda k: ssl_mod.ssl_init(k, encoder, SSLC),
+                           key)
+    step = server.make_calibration_step(encoder, SSLC, opt,
+                                        sub_layers=plan.sub_layers)
+    calib_text = step.lower(
+        state, jax.eval_shape(opt.init, state["online"]),
+        jax.ShapeDtypeStruct((16, 32, 32, 3), jnp.float32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((), jnp.float32)).as_text(debug_info=True)
+    return round_text, calib_text
+
+
+@pytest.fixture(scope="module")
+def stage2_scopes():
+    return [_scopes(t) for t in _stage2_programs()]
+
+
+@pytest.mark.parametrize("scope", [
+    "online", "target", "align", "frozen", "trained", "heads", "loss",
+    "augment", "optimizer", "wire", "fedavg"])
+def test_round_program_carries_scope(stage2_scopes, scope):
+    """The vmap round program of a layer-wise stage with alignment names
+    each branch of the client step and the round's wire and FedAvg
+    parts; the profiler's trace reads them back per op."""
+    assert scope in stage2_scopes[0]
+
+
+@pytest.mark.parametrize("scope", [
+    "calibrate", "augment", "online", "target", "frozen", "trained",
+    "heads", "loss", "optimizer"])
+def test_calibration_step_carries_scope(stage2_scopes, scope):
+    assert scope in stage2_scopes[1]
 
 
 def test_metrics_agree_with_history(traced_run):

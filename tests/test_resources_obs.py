@@ -66,6 +66,38 @@ def test_device_memory_snapshot_cpu():
                           "mem.peak_bytes"}
 
 
+class _FakeTPU:
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+@pytest.mark.parametrize("in_use_peak,reserved_peak", [(20, 50), (50, 20)])
+def test_device_memory_snapshot_reads_the_larger_peak(in_use_peak,
+                                                      reserved_peak):
+    """On TPU a program's temporaries count in ``peak_bytes_reserved``,
+    not in ``peak_bytes_in_use``: the snapshot's peak is the larger."""
+    snap = res_mod.device_memory_snapshot(_FakeTPU({
+        "bytes_in_use": 10, "peak_bytes_in_use": in_use_peak,
+        "peak_bytes_reserved": reserved_peak}))
+    assert snap == {"source": "device", "bytes_in_use": 10,
+                    "peak_bytes": 50}
+
+
+def test_memory_attrs_only_on_traced_rounds(monkeypatch):
+    """The driver queries device memory for the round span only when the
+    in-memory tracer records it: a health hook alone never pays for it."""
+    def no_query(*a, **k):
+        raise AssertionError("memory queried on an untraced run")
+
+    monkeypatch.setattr(res_mod, "memory_span_attrs", no_query)
+    _run(engine="vmap", obs=make_obs(health=True), rounds=1, schedule="e2e")
+
+
 def test_structure_ignores_mem_attrs():
     """mem.* attrs vary per machine/run; the determinism fingerprint
     must not see them (the driver stamps them on every round span)."""
