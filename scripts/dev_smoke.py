@@ -68,9 +68,9 @@ print("OK encdec", float(loss))
 vt = ModelConfig("t-vit", "dense", 2, 128, 4, 4, 256, 0, causal=False, compute_dtype="float32", act="gelu")
 pv = vit.init_vit(key, vt)
 imgs = jax.random.normal(key, (2, 32, 32, 3))
-rep = jax.jit(lambda p, x: vit.vit_forward(p, x, vt))(pv, imgs)
+rep = jax.jit(lambda p, x: vit.vit_suffix(p, vit.vit_prefix(p, x, vt), vt))(pv, imgs)
 assert rep.shape == (2, 128) and jnp.isfinite(rep).all()
-rep2 = jax.jit(lambda p, x: vit.vit_forward(p, x, vt, sub_layers=1, active_from=0))(pv, imgs)
+rep2 = jax.jit(lambda p, x: vit.vit_suffix(p, vit.vit_prefix(p, x, vt), vt, sub_layers=1))(pv, imgs)
 assert jnp.isfinite(rep2).all()
 print("OK vit")
 print("ALL MODELS OK")

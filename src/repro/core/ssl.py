@@ -5,9 +5,10 @@ State layout (a pytree, usable directly under pjit):
     {"online": {"enc": F, "proj": H, "pred": P},
      "target": {"enc": F_k, "proj": H_k}}
 
-The encoder is abstracted behind an ``Encoder`` record so the same SSL code
-drives the paper's ViT-Tiny on images and the assigned LM architectures on
-token sequences (representation = mean-pooled final hidden state).
+The encoder is abstracted behind an ``Encoder`` record whose forward comes
+in two parts: the frozen ``prefix`` and the ``suffix`` over the blocks the
+stage trains. At a layer-wise stage ``ssl_loss`` computes the prefix once
+per view and starts the online, target and alignment branches from it.
 
 MoCo v3 local loss with representation alignment is Algorithm 2 of the
 paper; ``momentum_update`` is the target-branch EMA; the server-side
@@ -16,7 +17,6 @@ calibration step (Algorithm 1, line 7) reuses ``ssl_loss`` with
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
@@ -34,10 +34,20 @@ from repro.models import vit as vit_mod
 @dataclass(frozen=True)
 class Encoder:
     init: Callable[..., Any]            # (key) -> params
-    apply: Callable[..., Any]           # (params, x, sub_layers, active_from,
+    prefix: Callable[..., Any]          # (params, x, active_from,
+    #                                      layer_gates) -> activations
+    suffix: Callable[..., Any]          # (params, h, sub_layers, active_from,
     #                                      layer_gates) -> (B, d_repr)
     d_repr: int
     num_stages: int
+
+    def apply(self, params, x, sub_layers=None, active_from=0,
+              layer_gates=None):
+        """The whole forward, ``suffix`` over ``prefix``."""
+        sub = self.num_stages if sub_layers is None else sub_layers
+        act = max(0, min(active_from, sub))
+        h = self.prefix(params, x, act, layer_gates)
+        return self.suffix(params, h, sub, act, layer_gates)
 
 
 def make_vit_encoder(cfg, image_size: int = 32, patch_size: int = 4,
@@ -47,27 +57,17 @@ def make_vit_encoder(cfg, image_size: int = 32, patch_size: int = 4,
     def init(key):
         return vit_mod.init_vit(key, cfg, image_size, patch_size)
 
-    def apply(params, x, sub_layers=None, active_from=0, layer_gates=None):
-        return vit_mod.vit_forward(params, x, cfg, patch_size=patch_size,
-                                   sub_layers=sub_layers,
-                                   active_from=active_from, remat=remat,
-                                   layer_gates=layer_gates)
+    def prefix(params, x, active_from=0, layer_gates=None):
+        return vit_mod.vit_prefix(params, x, cfg, patch_size=patch_size,
+                                  active_from=active_from, remat=remat,
+                                  layer_gates=layer_gates)
 
-    return Encoder(init, apply, cfg.d_model, cfg.num_layers)
+    def suffix(params, h, sub_layers=None, active_from=0, layer_gates=None):
+        return vit_mod.vit_suffix(params, h, cfg, sub_layers=sub_layers,
+                                  active_from=active_from, remat=remat,
+                                  layer_gates=layer_gates)
 
-
-def make_lm_encoder(cfg) -> Encoder:
-    """Token encoder: mean-pooled final hidden state of the (sub-)model."""
-    def init(key):
-        return lm_mod.init_lm(key, cfg)
-
-    def apply(params, tokens, sub_layers=None, active_from=0, layer_gates=None):
-        x = lm_mod.embed(params, tokens, cfg)
-        h, _ = lm_mod.forward_hidden(params, x, cfg, sub_layers=sub_layers,
-                                     active_from=active_from)
-        return jnp.mean(h.astype(jnp.float32), axis=1)
-
-    return Encoder(init, apply, cfg.d_model, lm_mod.num_stages(cfg))
+    return Encoder(init, prefix, suffix, cfg.d_model, cfg.num_layers)
 
 
 # ---------------------------------------------------------------------------
@@ -104,14 +104,26 @@ def momentum_update(state, mu: float):
 # ---------------------------------------------------------------------------
 # losses
 # ---------------------------------------------------------------------------
-def _branch(enc_params, head_params, pred_params, x, encoder: Encoder,
-            sub_layers, active_from, layer_gates=None):
-    z = encoder.apply(enc_params, x, sub_layers, active_from, layer_gates)
+def prefix_reuse(method: str, active_from: int, align: bool) -> int:
+    """Target and alignment branch-view forwards per step that start from
+    the shared frozen prefix instead of the patch embedding.
+
+    Within a round the three branches' prefixes ``[0, active_from)`` hold
+    the same weights: the target is re-copied from the broadcast, the
+    update mask keeps the online prefix as broadcast, and the alignment's
+    global encoder is the broadcast. The target's EMA of equal values
+    differs from them by rounding only."""
+    if active_from <= 0:
+        return 0
+    return 2 * (int(method != "simclr") + int(align))
+
+
+def _heads(z, head_params, pred_params):
     with jax.named_scope("heads"):
         p = heads.head_apply(head_params, z)
         if pred_params is not None:
             p = heads.head_apply(pred_params, p)
-    return z, p
+    return p
 
 
 def ssl_loss(state, x1, x2, encoder: Encoder, ssl_cfg, *,
@@ -121,28 +133,62 @@ def ssl_loss(state, x1, x2, encoder: Encoder, ssl_cfg, *,
 
     Returns (loss, metrics). ``global_enc`` (the broadcast global encoder) is
     only needed when ``align_weight > 0`` — representation alignment, Eq. 3.
+
+    At a layer-wise stage (``active_from > 0``) the frozen prefix is
+    computed once per view from the online weights, ungated, and every
+    branch whose prefix sees the same gates starts its suffix from it
+    (``prefix_reuse``); an online branch under depth-dropout gates runs
+    its own gated prefix.
     """
     o = state["online"]
     method = ssl_cfg.method
     tau = ssl_cfg.temperature
     if method not in ("moco_v3", "simclr", "byol"):
         raise ValueError(method)
+    sub = sub_layers or encoder.num_stages
+    act = max(0, min(active_from, sub))
+    views = (x1, x2)
 
     # the scopes name each branch in the HLO's op_name metadata, so a
     # profiler trace splits the step's device time by branch
+    shared = None
+    if prefix_reuse(method, act, align_weight > 0.0):
+        with jax.named_scope("online"):
+            shared = [encoder.prefix(o["enc"], x, act) for x in views]
+
+    prev = None     # the output of the last suffix run
+
+    def rep(enc_params, i, from_layer, gates=None):
+        """View i's representation, from the shared prefix when its gates
+        are the shared prefix's (none)."""
+        nonlocal prev
+        if shared is None or gates is not None:
+            return encoder.apply(enc_params, views[i], sub, from_layer,
+                                 gates)
+        h = shared[i]
+        if prev is not None:
+            # one suffix after another: left free, XLA runs the branches'
+            # suffixes side by side and holds all their temporaries at
+            # once (0.8 GB more at ViT-Tiny, 8 clients, batch 1024)
+            h = jax.lax.optimization_barrier(
+                (h, jax.lax.stop_gradient(prev)))[0]
+        prev = encoder.suffix(enc_params, h, sub, act)
+        return prev
+
     pred = None if method == "simclr" else o["pred"]
     with jax.named_scope("online"):
-        z1, q1 = _branch(o["enc"], o["proj"], pred, x1, encoder,
-                         sub_layers, active_from, layer_gates)
-        z2, q2 = _branch(o["enc"], o["proj"], pred, x2, encoder,
-                         sub_layers, active_from, layer_gates)
+        z1 = rep(o["enc"], 0, active_from, layer_gates)
+        q1 = _heads(z1, o["proj"], pred)
+        z2 = rep(o["enc"], 1, active_from, layer_gates)
+        q2 = _heads(z2, o["proj"], pred)
     if method != "simclr":
         t = state["target"]
         with jax.named_scope("target"):
-            _, k1 = _branch(t["enc"], t["proj"], None, x1, encoder,
-                            sub_layers, sub_layers or encoder.num_stages)
-            _, k2 = _branch(t["enc"], t["proj"], None, x2, encoder,
-                            sub_layers, sub_layers or encoder.num_stages)
+            # the target is never differentiated
+            k1 = _heads(jax.lax.stop_gradient(rep(t["enc"], 0, sub)),
+                        t["proj"], None)
+            k2 = _heads(jax.lax.stop_gradient(rep(t["enc"], 1, sub)),
+                        t["proj"], None)
     with jax.named_scope("loss"):
         if method == "moco_v3":
             loss = losses.moco_contrastive(q1, k2, q2, k1, tau)
@@ -156,8 +202,8 @@ def ssl_loss(state, x1, x2, encoder: Encoder, ssl_cfg, *,
     if align_weight > 0.0:
         assert global_enc is not None, "alignment needs the global encoder"
         with jax.named_scope("align"):
-            zg1 = encoder.apply(global_enc, x1, sub_layers, 0)
-            zg2 = encoder.apply(global_enc, x2, sub_layers, 0)
+            zg1 = rep(global_enc, 0, 0)
+            zg2 = rep(global_enc, 1, 0)
         with jax.named_scope("loss"):
             la = losses.align_loss(z1, zg2, z2, zg1, tau)
             loss = loss + align_weight * la

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import ssl as ssl_mod
 from repro.data.partition import stack_shards
 from repro.federated import aggregate, client as client_mod
 from repro.federated import transport as transport_mod
@@ -62,7 +63,6 @@ def _pool_len(pool) -> int:
 def _abstract_round_inputs(encoder, ssl_cfg, opt, images, batch_size):
     """Shape-only (eval_shape) state/opt/batch trees for AOT lowering —
     no parameters are materialized."""
-    from repro.core import ssl as ssl_mod
     state = jax.eval_shape(
         lambda k: ssl_mod.ssl_init(k, encoder, ssl_cfg),
         jax.random.PRNGKey(0))
@@ -83,6 +83,14 @@ def jit_cache_entries(fns) -> int:
         if size is not None:
             total += size()
     return total
+
+
+def step_prefix_reuse(ssl_cfg, plan) -> int:
+    """``ssl.prefix_reuse`` of the local step built for ``plan``: target
+    and alignment branch-view forwards a step that start from the shared
+    frozen prefix."""
+    return ssl_mod.prefix_reuse(ssl_cfg.method, plan.active_from,
+                                plan.align and ssl_cfg.align_weight > 0.0)
 
 
 def build_round_program(client_init, client_step, extract,
@@ -216,10 +224,11 @@ class SequentialEngine:
                   global_enc, server_online, collect=False):
         tracer = self.obs.tracer
         step_fn = self._step(plan)
+        reuse = step_prefix_reuse(self.ssl_cfg, plan)
         outs, losses = [], []
         for i, kc in zip(participants, client_keys):
             with tracer.span("client.train", cat="engine",
-                             client=int(i)) as sp:
+                             client=int(i), prefix_reuse=reuse) as sp:
                 online_i, m = client_mod.local_train(
                     state, self.images[self.client_indices[i]], step_fn,
                     self.opt, epochs=self.fl.local_epochs,
@@ -380,7 +389,9 @@ class VmapEngine:
         # the program is enqueued, and the wait for it is engine.readback
         with tracer.span("engine.dispatch", cat="engine",
                          engine=self.name, participants=len(participants),
-                         programs=len(self._programs)):
+                         programs=len(self._programs),
+                         prefix_reuse=step_prefix_reuse(self.ssl_cfg,
+                                                        plan)):
             result, losses, new_res, scales = self._program(
                 plan, spec, fedavg=not collect)(
                 {"state": state, "global_enc": global_enc,

@@ -2,9 +2,10 @@
 
 Matches the paper's setup: 32x32x3 inputs, patch size 4 (trained patch
 projection, per MoCo v3 deviation noted in the paper), learned positional
-embeddings, CLS token, 12 blocks. Supports the layer-wise stage interface
-(``sub_layers``, ``active_from``) used by FedMoCo-LW / LW-FedSSL /
-Prog-FedSSL.
+embeddings, CLS token, 12 blocks. The forward is ``vit_suffix`` over
+``vit_prefix``, split at the layer-wise stage interface (``active_from``,
+``sub_layers``) used by FedMoCo-LW / LW-FedSSL / Prog-FedSSL, so branches
+that share the frozen prefix can compute it once.
 """
 from __future__ import annotations
 
@@ -45,23 +46,9 @@ def patchify(images, patch_size: int):
     return x.reshape(Bsz, ph * pw, patch_size * patch_size * C)
 
 
-def vit_forward(params, images, cfg, *, patch_size: int = 4,
-                sub_layers=None, active_from: int = 0, remat: bool = False,
-                layer_gates=None):
-    """Returns CLS representation (B, d_model).
-
-    layer_gates: optional (num_layers,) float gates multiplying each block's
-    residual delta (depth dropout for FLL+DD; 1.0 = keep, 0.0 = skip).
-    """
-    x = patchify(images, patch_size).astype(jnp.dtype(cfg.param_dtype))
-    x = x @ params["patch"]
-    Bsz = x.shape[0]
-    cls = jnp.broadcast_to(params["cls"], (Bsz, 1, cfg.d_model))
-    x = jnp.concatenate([cls, x], axis=1) + params["pos"][None]
-
-    sub = cfg.num_layers if sub_layers is None else sub_layers
-    act = max(0, min(active_from, sub))
-
+def _scan_blocks(params, x, cfg, lo: int, hi: int, layer_gates, remat):
+    """Blocks ``[lo, hi)`` over ``x``; each gate multiplies its block's
+    residual delta."""
     def body(carry, pg):
         x, _ = carry
         p, g = pg
@@ -74,18 +61,40 @@ def vit_forward(params, images, cfg, *, patch_size: int = 4,
 
     gates = (jnp.ones((cfg.num_layers,), jnp.float32)
              if layer_gates is None else layer_gates)
-    if act > 0:
+    (x, _), _ = jax.lax.scan(
+        body, (x, jnp.float32(0.0)),
+        (_slice_stack(params["blocks"], lo, hi), gates[lo:hi]),
+        unroll=scan_cfg.scan_unroll())
+    return x
+
+
+def vit_prefix(params, images, cfg, *, patch_size: int = 4,
+               active_from: int = 0, remat: bool = False, layer_gates=None):
+    """Patch embedding, CLS and positions, then the frozen blocks
+    ``[0, active_from)``: (B, tokens, d_model) activations that carry no
+    gradient. Every branch whose prefix has these weights and gates can
+    start its suffix from them."""
+    x = patchify(images, patch_size).astype(jnp.dtype(cfg.param_dtype))
+    x = x @ params["patch"]
+    Bsz = x.shape[0]
+    cls = jnp.broadcast_to(params["cls"], (Bsz, 1, cfg.d_model))
+    x = jnp.concatenate([cls, x], axis=1) + params["pos"][None]
+    if active_from > 0:
         with jax.named_scope("frozen"):
-            (x, _), _ = jax.lax.scan(
-                body, (x, jnp.float32(0.0)),
-                (_slice_stack(params["blocks"], 0, act), gates[0:act]),
-                unroll=scan_cfg.scan_unroll())
+            x = _scan_blocks(params, x, cfg, 0, active_from, layer_gates,
+                             remat)
             x = jax.lax.stop_gradient(x)
-    if sub > act:
+    return x
+
+
+def vit_suffix(params, x, cfg, *, sub_layers=None, active_from: int = 0,
+               remat: bool = False, layer_gates=None):
+    """The blocks ``[active_from, sub_layers)`` over a prefix's
+    activations, the final norm and the CLS read-out (B, d_model)."""
+    sub = cfg.num_layers if sub_layers is None else sub_layers
+    if sub > active_from:
         with jax.named_scope("trained"):
-            (x, _), _ = jax.lax.scan(
-                body, (x, jnp.float32(0.0)),
-                (_slice_stack(params["blocks"], act, sub), gates[act:sub]),
-                unroll=scan_cfg.scan_unroll())
+            x = _scan_blocks(params, x, cfg, active_from, sub, layer_gates,
+                             remat)
     x = B.rmsnorm(params["final_ln"], x, cfg.norm_eps)
     return x[:, 0]

@@ -113,18 +113,24 @@ def vit_costs(cfg=None, ssl=None) -> VitCosts:
 # per-round client costs by schedule
 # ---------------------------------------------------------------------------
 def flops_per_sample_round(c: VitCosts, plan) -> float:
-    """MoCo v3 local step FLOPs for one sample in one round (2 views)."""
+    """MoCo v3 local step FLOPs for one sample in one round (2 views).
+
+    With a frozen prefix and no depth-dropout gates the target and
+    alignment branches start from the online branch's prefix
+    (``core.ssl.ssl_loss``), so each costs its ``s - act`` blocks only."""
     s, act = plan.sub_layers, plan.active_from
     fwd_frozen = c.f_stem + act * c.f_block
     fwd_active = (s - act) * c.f_block + c.f_proj + c.f_pred
     online = 2 * (fwd_frozen + fwd_active)              # 2 views
-    target = 2 * (c.f_stem + s * c.f_block + c.f_proj)  # EMA branch, fwd only
+    if act > 0 and plan.depth_dropout == 0:
+        branch = (s - act) * c.f_block                  # shared prefix
+    else:
+        branch = c.f_stem + s * c.f_block
+    target = 2 * (branch + c.f_proj)                    # EMA branch, fwd only
     bwd = 2 * 2 * fwd_active                            # 2:1 ratio, 2 views
-    if act > 0:
-        bwd += 2 * 2 * 0                                # frozen: no backward
-    total = online + target + bwd
+    total = online + target + bwd                       # frozen: no backward
     if plan.align:
-        total += 2 * (c.f_stem + s * c.f_block)         # global model fwd
+        total += 2 * branch                             # global model fwd
     return total
 
 

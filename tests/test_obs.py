@@ -234,17 +234,25 @@ def test_vmap_round_spans_in_the_profiler_trace(tmp_path, traced):
 _TRANSFORM = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
 
 
-def _scopes(hlo_text):
-    """The scope-like path components of the lowered program's op
-    locations, transforms stripped (``transpose(jvp(online))`` ->
+def _op_paths(hlo_text):
+    """Each op location of the lowered program as the set of its path
+    components, transforms stripped (``transpose(jvp(online))`` ->
     ``online``)."""
-    out = set()
+    out = []
     for loc in re.findall(r'loc\("([^"]*)"', hlo_text):
+        parts = set()
         for part in loc.split("/"):
             while (m := _TRANSFORM.match(part)):
                 part = m.group(1)
-            out.add(part)
+            parts.add(part)
+        out.append(parts)
     return out
+
+
+def _scopes(hlo_text):
+    """The scope-like path components of the lowered program's op
+    locations."""
+    return set().union(*_op_paths(hlo_text))
 
 
 def _stage2_programs():
@@ -274,8 +282,13 @@ def _stage2_programs():
 
 
 @pytest.fixture(scope="module")
-def stage2_scopes():
-    return [_scopes(t) for t in _stage2_programs()]
+def stage2_programs():
+    return _stage2_programs()
+
+
+@pytest.fixture(scope="module")
+def stage2_scopes(stage2_programs):
+    return [_scopes(t) for t in stage2_programs]
 
 
 @pytest.mark.parametrize("scope", [
@@ -293,6 +306,20 @@ def test_round_program_carries_scope(stage2_scopes, scope):
     "heads", "loss", "optimizer"])
 def test_calibration_step_carries_scope(stage2_scopes, scope):
     assert scope in stage2_scopes[1]
+
+
+def test_round_program_computes_frozen_prefix_once(stage2_programs):
+    """At a layer-wise stage with alignment the frozen prefix runs only
+    under ``online``: the target and alignment branches start from it, so
+    no op of theirs is a frozen block. Server calibration trains end to
+    end, shares nothing, and its target still runs every block frozen."""
+    round_paths, calib_paths = (_op_paths(t) for t in stage2_programs)
+    assert any({"online", "frozen"} <= p for p in round_paths)
+    assert not [p for p in round_paths
+                if "frozen" in p and p & {"target", "align"}]
+    assert any({"target", "trained"} <= p for p in round_paths)
+    assert any({"align", "trained"} <= p for p in round_paths)
+    assert any({"target", "frozen"} <= p for p in calib_paths)
 
 
 def test_metrics_agree_with_history(traced_run):
