@@ -1,9 +1,9 @@
 """Analytic model FLOPs of a federated MoCo v3 round on a ViT.
 
 The benchmark's own copy of the program's per-sample accounting
-(``repro.roofline.client_costs.flops_per_sample_round``): dense
-multiply-adds counted as 2 FLOPs, backward = 2x the forward of the
-trainable part, nothing for remat recompute. Server calibration is a
+(``repro.roofline.client_costs.flops_per_sample_round``, without depth
+dropout): dense multiply-adds counted as 2 FLOPs, backward = 2x the
+forward of the trainable part, nothing for remat recompute. Server calibration is a
 step over the whole current sub-model with no alignment.
 """
 from __future__ import annotations
@@ -54,16 +54,20 @@ class VitCosts:
 
 def step_flops(c: VitCosts, *, sub: int, active_from: int,
                align: bool) -> float:
-    """FLOPs of one sample (both views) in one local step."""
+    """FLOPs of one sample (both views) in one local step. With a frozen
+    prefix (``active_from > 0``) the online weights' prefix runs once per
+    view and the target and alignment branches start from it, so each
+    costs its ``sub - active_from`` blocks only."""
     act = active_from
     fwd_frozen = c.stem + act * c.block
     fwd_active = (sub - act) * c.block + c.proj + c.pred
     online = 2 * (fwd_frozen + fwd_active)
-    target = 2 * (c.stem + sub * c.block + c.proj)
+    branch = (sub - act) * c.block if act > 0 else c.stem + sub * c.block
+    target = 2 * (branch + c.proj)
     bwd = 2 * 2 * fwd_active
     total = online + target + bwd
     if align:
-        total += 2 * (c.stem + sub * c.block)
+        total += 2 * branch
     return float(total)
 
 

@@ -4,7 +4,10 @@ correctness comparison and the result line.
 Everything that belongs to a cell is data found by name: the cell in
 ``BENCHMARK.json``, its configuration file, ``traffic/<traffic>.json``,
 ``limits/<cell>.json`` and one reader ``metrics/<metric>.py`` per
-per-layer metric.
+per-layer metric. What reads the configuration's model keys (the
+program's configuration objects, the inputs, the plain reference and
+the FLOP count) is the module ``families/<family>.py`` that the
+configuration's top-level ``family`` names.
 
 The window drives the program's own entry, ``repro.federated.driver.
 run_fedssl``, once per run, with the vmap engine. The harness sees round
@@ -81,14 +84,33 @@ def cell_metrics(spec, cell, kind):
             if "workloads" not in m or cell["name"] in m["workloads"]]
 
 
-def load_reader(name, bench_dir=BENCH_DIR):
-    """``read(ctx)`` of ``metrics/<name>.py``."""
-    path = pathlib.Path(bench_dir) / "metrics" / f"{name}.py"
+def _load_module(kind, name, bench_dir):
+    """The module ``<kind>/<name>.py`` of the benchmark, by file path."""
+    path = pathlib.Path(bench_dir) / kind / f"{name}.py"
     mod_spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_"), path)
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"),
+        path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name, bench_dir=BENCH_DIR):
+    """``read(ctx)`` of ``metrics/<name>.py``."""
+    return _load_module("metrics", name, bench_dir).read
+
+
+def load_family(cfg, bench_dir=BENCH_DIR):
+    """The module ``families/<family>.py`` of the configuration's
+    ``family`` (its interface: ``families/vit.py``)."""
+    name = cfg.get("family")
+    if (not isinstance(name, str)
+            or not (pathlib.Path(bench_dir) / "families"
+                    / f"{name}.py").is_file()):
+        raise BenchError(f"configuration {cfg.get('name')!r} names model "
+                         f"family {name!r}, which has no "
+                         f"families/<family>.py")
+    return _load_module("families", name, bench_dir)
 
 
 def device_peak(device_kind, bench_dir=BENCH_DIR):
@@ -102,16 +124,11 @@ def device_peak(device_kind, bench_dir=BENCH_DIR):
 # ---------------------------------------------------------------------------
 # the program's configuration objects, built from the cell's files
 # ---------------------------------------------------------------------------
-def program_configs(cfg, traffic):
-    from repro.configs.base import (FLConfig, ModelConfig, SSLConfig,
-                                    TrainConfig)
-    if cfg["patch_size"] != 4:
-        raise BenchError("the program's ViT path patchifies by 4")
-    m = dict(cfg["model"])
-    model = ModelConfig(arch_id=cfg["name"], family="dense", vocab_size=0,
-                        causal=False, num_kv_heads=m["num_heads"], **m)
-    ssl = SSLConfig(**cfg["ssl"])
-    train = TrainConfig(batch_size=traffic["batch"], **cfg["train"])
+def program_configs(family, cfg, traffic):
+    """(model, ssl, train, fl): the family's three, and the federated
+    plan from the traffic."""
+    from repro.configs.base import FLConfig
+    model, ssl, train = family.program_configs(cfg, traffic)
     L = model.num_layers
     if traffic["schedule"] == "lw_fedssl":
         s = traffic["stage"]
@@ -243,7 +260,6 @@ class Run:
         import jax
         from repro.launch.compile_cache import enable_compile_cache
 
-        from chipbench import data
         self.spec, self.cell, self.cfg, self.traffic, self.limits = (
             cell_files or load_cell)(workload, root, bench_dir)
         self.devices = jax.devices()
@@ -261,9 +277,8 @@ class Run:
         self.root, self.bench_dir, self.seed = root, bench_dir, seed
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        self.pool, self.shards, self.aux, self.key = data.make_inputs(
-            seed, self.traffic, self.cfg["image_size"])
-        jax.block_until_ready(self.pool)
+        self.family = load_family(self.cfg, bench_dir)
+        self.inputs = self.family.make_inputs(seed, self.traffic, self.cfg)
 
     def drive(self, window):
         """``run_fedssl`` over the cell's traffic until ``window`` halts
@@ -271,15 +286,16 @@ class Run:
         import jax
         from repro.federated.driver import run_fedssl
         from repro.obs import Observability
-        model, ssl, train, fl = program_configs(self.cfg, self.traffic)
-        tr = self.traffic
+        model, ssl, train, fl = program_configs(self.family, self.cfg,
+                                                self.traffic)
+        tr, inputs = self.traffic, self.inputs
         state, _ = run_fedssl(
-            model, ssl, fl, train, images=self.pool,
-            client_indices=[jax.numpy.asarray(s) for s in self.shards],
-            aux_images=self.aux, key=self.key,
-            image_size=self.cfg["image_size"], engine=tr["engine"],
-            codec=tr["codec"], transport_kernels=tr["transport_kernels"],
-            obs=Observability(health=window))
+            model, ssl, fl, train,
+            client_indices=[jax.numpy.asarray(s) for s in inputs.shards],
+            key=inputs.key, engine=tr["engine"], codec=tr["codec"],
+            transport_kernels=tr["transport_kernels"],
+            obs=Observability(health=window),
+            **self.family.run_kwargs(inputs, self.cfg))
         if not window.should_halt:
             raise BenchError(f"the traffic's {tr['rounds']} rounds ended "
                              f"before the window did")
@@ -290,12 +306,10 @@ class Run:
         [their mean last-step client losses], [their first-gradient
         norms]) of the plain reference, on the host; ``numerics="control"``
         gives the control."""
-        from chipbench import compare, reference
+        from chipbench import compare
         t = time.perf_counter()
-        ref = reference.RoundRef(self.cfg, self.traffic,
-                                 reference.Numerics(numerics))
-        init, rounds = ref.rounds(self.key, self.pool, self.shards,
-                                  self.aux, COMPARED_ROUNDS)
+        init, rounds = self.family.reference_rounds(
+            self.cfg, self.traffic, numerics, self.inputs, COMPARED_ROUNDS)
         say(f"{numerics} rounds 0-{COMPARED_ROUNDS - 1} in "
             f"{time.perf_counter() - t:.1f}s, losses "
             f"{[r[1] for r in rounds]!r}")
@@ -332,7 +346,7 @@ def run_cell(workload, seed, seconds, trace, *, t_start, **run_kw):
     """One benchmark run; returns the result object (the last line)."""
     import jax
 
-    from chipbench import compare, flops, xplane
+    from chipbench import compare, scopes, xplane
 
     run = Run(workload, seed, **run_kw)
     cell, traffic = run.cell, run.traffic
@@ -368,15 +382,16 @@ def run_cell(workload, seed, seconds, trace, *, t_start, **run_kw):
     ctx = SimpleNamespace(
         cell=cell, cfg=run.cfg, traffic=traffic, rounds=window.rounds,
         window_s=window_s, compiles=n_compiles,
-        round_flops=flops.round_flops(run.cfg, traffic), peak=run.peak,
-        trace=None)
+        round_flops=run.family.round_flops(run.cfg, traffic), peak=run.peak,
+        trace=None, scopes=None)
     device = {"platform": run.dev.platform, "kind": run.dev.device_kind,
               "count": len(run.devices), "memory_peak_bytes": mem_peak}
     result = {"correct": bool(correct and failed == 0),
               "attempted": window.rounds, "failed": failed}
     if trace:
-        ctx.trace = xplane.reduce_trace(xplane.find_xplane(trace_dir),
-                                        cell["chips"])
+        path = xplane.find_xplane(trace_dir)
+        ctx.trace = xplane.reduce_trace(path, cell["chips"])
+        ctx.scopes = scopes.reduce_trace(path, cell["chips"])
         shutil.rmtree(trace_dir, ignore_errors=True)
         device["busy_s"] = ctx.trace.busy_s
         device["window_s"] = window_s

@@ -5,12 +5,11 @@
 
 For every seed, in one process: the cell's window, as a benchmark run
 drives it (``harness.Run`` and ``harness.Window``), with the profiler on
-from the window's opening to its close. The trace is reduced twice: by
-``chipbench.xplane`` to the cell's per-layer metrics as their readers
-read them, and by ``chipbench.scopes`` to the device time of each
-program scope and the host time the device waited on
-(``scopes.readings``), with the share of the round program's op time
-that carries a scope and the traced window's client samples per second.
+from the window's opening to its close. The trace is reduced by
+``chipbench.xplane`` and ``chipbench.scopes`` to the cell's per-layer
+metrics as a benchmark run's readers read them, with the share of each
+program's op time that carries a scope, the heaviest op paths, the host
+spans and the traced window's client samples per second.
 No reference runs. ``--keep`` copies each run's ``.xplane.pb`` there.
 The benchmark's own runs never run this.
 """
@@ -40,7 +39,6 @@ def top_paths(trace, n, keep=lambda p: True):
 
 
 def read(workload, seed, seconds, keep=None, **run_kw):
-    from chipbench import flops
     run = harness.Run(workload, seed, **run_kw)
     trace_dir = pathlib.Path(run.root) / ".bench_trace" / "scopes"
     shutil.rmtree(trace_dir, ignore_errors=True)
@@ -58,19 +56,18 @@ def read(workload, seed, seconds, keep=None, **run_kw):
         pathlib.Path(keep).mkdir(parents=True, exist_ok=True)
         shutil.copy(path, pathlib.Path(keep) / f"{workload}.{seed}.xplane.pb")
     chips = run.cell["chips"]
+    st = scopes.reduce_trace(path, chips)
     ctx = SimpleNamespace(
         cell=run.cell, cfg=run.cfg, traffic=run.traffic,
         rounds=window.rounds, window_s=window_s, compiles=n_compiles,
-        round_flops=flops.round_flops(run.cfg, run.traffic), peak=run.peak,
-        trace=xplane.reduce_trace(path, chips))
-    st = scopes.reduce_trace(path, chips)
+        round_flops=run.family.round_flops(run.cfg, run.traffic),
+        peak=run.peak, trace=xplane.reduce_trace(path, chips), scopes=st)
     shutil.rmtree(trace_dir, ignore_errors=True)
     metrics = {}
     for m in harness.cell_metrics(run.spec, run.cell, "per_layer"):
         v = harness.load_reader(m["name"], run.bench_dir)(ctx)
         if v is not None:
             metrics[m["name"]] = v
-    metrics.update(scopes.readings(st, window.rounds))
     return {
         "seed": seed, "rounds": window.rounds, "window_s": window_s,
         "busy_s": ctx.trace.busy_s,

@@ -10,12 +10,16 @@ import chipbench_tiny as tiny
 from chipbench import compare, faults, harness, reference
 
 LW = "vit-tiny.lw_fedssl.s12"
-SCHEDULES = ["lw_fedssl", "e2e"]    # the cell's own, and FedMoCo's plan
+# (cell, schedule in place of the traffic's own): the LW cell with its
+# own plan and with FedMoCo's, and the e2e cell
+CASES = [pytest.param((LW, None), id="lw_fedssl"),
+         pytest.param((LW, "e2e"), id="e2e"),
+         pytest.param(("vit-small.e2e", None), id="vit-small.e2e")]
 
 
-@pytest.mark.parametrize("schedule", SCHEDULES)
-def test_a_tiny_run_is_correct_and_reports_its_metrics(schedule):
-    result = tiny.run_tiny(LW, schedule=schedule)
+@pytest.mark.parametrize("case", CASES)
+def test_a_tiny_run_is_correct_and_reports_its_metrics(case):
+    result = tiny.run_tiny(case[0], schedule=case[1])
     assert result["correct"], result["checks"]
     assert result["attempted"] >= 1 and result["failed"] == 0
     assert set(result["metrics"]) == {
@@ -29,7 +33,8 @@ def test_the_reference_builds_the_programs_initial_weights():
     from repro.core import ssl as ssl_mod
     from repro.configs.base import SSLConfig
     _, _, cfg, traffic, _ = tiny.tiny_cell(LW)
-    model, ssl, train, _ = harness.program_configs(cfg, traffic)
+    model, ssl, train, _ = harness.program_configs(
+        harness.load_family(cfg), cfg, traffic)
     key = jax.random.PRNGKey(3)
     prog = ssl_mod.ssl_init(key, ssl_mod.make_vit_encoder(model), ssl)
     ref = reference.init_state(key, cfg)
@@ -40,11 +45,11 @@ def test_the_reference_builds_the_programs_initial_weights():
                                       err_msg=p)
 
 
-@pytest.mark.parametrize("schedule", SCHEDULES)
+@pytest.mark.parametrize("case", CASES)
 @pytest.mark.parametrize("kind", faults.FAULTS)
-def test_a_planted_fault_is_not_correct(kind, schedule):
+def test_a_planted_fault_is_not_correct(kind, case):
     with faults.planted(kind):
-        result = tiny.run_tiny(LW, seed=777, schedule=schedule)
+        result = tiny.run_tiny(case[0], seed=777, schedule=case[1])
     assert not result["correct"], result["checks"]
 
 
@@ -58,10 +63,11 @@ def test_a_fault_of_later_rounds_is_not_correct(kind):
     assert not result["correct"], checks
 
 
-@pytest.mark.parametrize("schedule", SCHEDULES)
-def test_the_control_is_not_correct(schedule):
-    run = harness.Run(LW, 4242, require_tpu=False,
-                      cell_files=lambda *_: tiny.tiny_cell(LW, schedule))
+@pytest.mark.parametrize("case", CASES)
+def test_the_control_is_not_correct(case):
+    cell, schedule = case
+    run = harness.Run(cell, 4242, require_tpu=False,
+                      cell_files=lambda *_: tiny.tiny_cell(cell, schedule))
     ref = run.reference()
     _, online, losses, _ = run.reference("control")
     ok, checks = compare.judge(run.numbers(ref, online, losses),

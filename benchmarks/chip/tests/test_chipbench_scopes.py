@@ -43,6 +43,18 @@ def test_recorded_readings(recorded):
         "engine.optimizer_ms": 0.016275, "driver.exposed_ms": 100.849157})
 
 
+@pytest.mark.parametrize("name", sorted(scopes.READINGS)
+                         + ["driver.exposed_ms"])
+def test_each_scope_reader_reads_its_reading(recorded, name):
+    """The per-layer metric's reader, as a traced run calls it, on the
+    recorded trace; nothing where no trace was reduced."""
+    from chipbench import harness
+    read = harness.load_reader(name)
+    want = scopes.readings(recorded[1], rounds=2)[name]
+    assert read(SimpleNamespace(scopes=recorded[1], rounds=2)) == want
+    assert read(SimpleNamespace(scopes=None, rounds=2)) is None
+
+
 def test_recorded_scoped_shares(recorded):
     t = recorded[1]
     assert t.scoped_share() == pytest.approx(0.9063819833600458)

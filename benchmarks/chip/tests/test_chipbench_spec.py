@@ -22,6 +22,7 @@ def test_every_entry_resolves_to_its_files():
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert cfg["name"] == c["name"]
         assert cfg["reduced"] == c["reduced"]
+        assert (BENCH / "families" / f"{cfg['family']}.py").is_file()
     for w in s["workloads"]:
         assert w["config"] in configs
         assert (BENCH / "traffic" / f"{w['traffic']}.json").is_file()
